@@ -87,14 +87,34 @@ def save_bench(suite: str, rows: list) -> str:
     return path
 
 
+def cpu_child_env(what: str) -> dict:
+    """Environment for a child process that rehearses on forced CPU host
+    devices (``--xla_force_host_platform_device_count``, a CPU-only
+    recipe).  The child is pinned to the CPU, so it never asks for an
+    accelerator.  Raises when THIS process runs on an accelerator: its
+    numbers would then be CPU numbers under a device benchmark's name,
+    and a parent that has touched the chip holds it."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} runs in child processes on forced CPU host devices, "
+            f"a CPU-only rehearsal; this process runs on {backend}, so "
+            "it is refused here (run the shapes in-process over the real "
+            "devices instead)")
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                PYTHONPATH=os.path.join(REPO, "src"))
+
+
 def run_shard_worker(workload: str, devices: int, policy: str = "static",
                      exchange: str = "window", scale: float = SIM_SCALE,
                      timeout: int = 900) -> dict:
     """Run one sharded simulation in a subprocess with `devices` host
-    devices (jax locks the device count per process)."""
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(REPO, "src"))
+    devices (jax locks the device count per process).  CPU only
+    (``cpu_child_env``)."""
+    env = dict(cpu_child_env("the sharded-mode worker"),
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
     cmd = [sys.executable, "-m", "benchmarks.shard_worker",
            "--workload", workload, "--devices", str(devices),
            "--policy", policy, "--exchange", exchange,

@@ -6,7 +6,8 @@ wall-clock multi-device scaling is not physically observable — DESIGN.md §7):
   a. measured: sequential (lax.map over SMs) vs vectorized (vmap) wall time
      — the single-chip SIMD speed-up of the parallel region;
   b. measured: sharded-mode wall time at 1/2/4/8/16 host devices
-     (subprocess per count; flat on one core, reported honestly);
+     (subprocess per count; flat on one core, reported honestly; a CPU
+     rehearsal only — refused on an accelerator, common.cpu_child_env);
   c. modeled: Amdahl speed-up from the *measured deterministic work
      distribution* — parallel work = per-SM active-warp-cycles, serial work
      = memory-system events — reproducing the paper's curve shapes
@@ -75,13 +76,8 @@ def run(benches=None, shard_devices=(2, 8, 16),
             "derived": ";".join(f"x{d}={v}" for d, v in model.items()),
         })
         if measure_shard and name in SHARD_BENCHES:
-            walls = {}
-            for d in shard_devices:
-                try:
-                    r = run_shard_worker(name, d)
-                    walls[d] = round(r["wall_s"], 3)
-                except Exception as e:  # noqa: BLE001
-                    walls[d] = f"err:{type(e).__name__}"
+            walls = {d: round(run_shard_worker(name, d)["wall_s"], 3)
+                     for d in shard_devices}
             rows.append({
                 "name": f"fig5/{name}/sharded_wall",
                 "us_per_call": 0.0,

@@ -1,11 +1,14 @@
 """Mesh-shape throughput: configs/sec of a distributed grid sweep vs the
 2-D ('cfg', 'sm') mesh shape (core/distribute.py).
 
-Each mesh shape runs in a SUBPROCESS with
+On the CPU each mesh shape runs in a SUBPROCESS with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=<A*B>`` — jax locks the
 host device count at first init, so forcing it per shape is the only way
 to sweep shapes from one driver (same recipe as fig5's shard workers; see
-benchmarks/README.md).  This container has one physical core, so forced
+benchmarks/README.md).  On an accelerator every shape runs in THIS
+process over the real devices (a child could not get the chip this
+process holds); a shape needing more devices than there are fails.
+This container has one physical core, so forced
 host devices time-slice it: the numbers establish the *trajectory
 harness* (BENCH_mesh.json artifacts in CI) and prove every shape runs;
 real scaling needs real devices.  Lane results are bit-exact at every
@@ -18,12 +21,11 @@ trustworthy stand-ins for the expensive ones.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import time
 
-from benchmarks.common import REPO, SIM_SCALE, save_json
+from benchmarks.common import REPO, SIM_SCALE, cpu_child_env, save_json
 
 MESH_SHAPES = ((1, 1), (2, 1), (1, 2), (2, 2), (4, 1))
 N_WORKLOADS = 2
@@ -92,9 +94,8 @@ def worker(n_cfg: int, n_sm: int) -> None:
 
 def run_mesh_worker(n_cfg: int, n_sm: int, timeout: int = 1200) -> dict:
     env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_cfg * n_sm}",
-        PYTHONPATH=os.path.join(REPO, "src"))
+        cpu_child_env("the mesh-shape worker"),
+        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_cfg * n_sm}")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.mesh_sweep",
          "--worker", str(n_cfg), str(n_sm)],
@@ -105,24 +106,22 @@ def run_mesh_worker(n_cfg: int, n_sm: int, timeout: int = 1200) -> dict:
 
 
 def run(shapes=MESH_SHAPES, fast: bool = False) -> list[dict]:
+    import jax
+
     if fast:  # honor run.py --fast: NO subprocess sweeps — just the
         shapes = ((1, 1),)  # in-process single-device anchor
+    in_process = fast or jax.default_backend() != "cpu"
     rows = []
     results = {}
     checks = set()
     for a, b in shapes:
-        try:
-            r = bench_one(a, b) if fast else run_mesh_worker(a, b)
-            results[f"{a}x{b}"] = r
-            checks.add(r["cycles_check"])
-            us = r["wall_s"] * 1e6
-            derived = (f"lanes_per_s={r['lanes_per_s']:.2f};"
-                       f"compile_s={r['compile_s']:.1f}")
-        except Exception as e:  # noqa: BLE001
-            us = -1.0
-            derived = f"err:{type(e).__name__}"
+        r = bench_one(a, b) if in_process else run_mesh_worker(a, b)
+        results[f"{a}x{b}"] = r
+        checks.add(r["cycles_check"])
         rows.append({"name": f"mesh/grid_{a}x{b}",
-                     "us_per_call": us, "derived": derived})
+                     "us_per_call": r["wall_s"] * 1e6,
+                     "derived": (f"lanes_per_s={r['lanes_per_s']:.2f};"
+                                 f"compile_s={r['compile_s']:.1f}")})
     # every shape must agree on total simulated cycles (cheap cross-check;
     # the bit-exact per-lane lock lives in tests/test_mesh_sweep.py)
     assert len(checks) <= 1, f"mesh shapes disagree on cycles: {results}"

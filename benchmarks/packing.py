@@ -107,7 +107,7 @@ def run() -> list[dict]:
         for g, s in zip(groups, stacks):
             _, tm = timed_call(runner, batched_init(scfg, len(g), N_CONFIGS),
                                s, dyn_batch, n_lanes=lanes, cache_key=key)
-            compile_s += tm["compile_s"] or 0.0
+            compile_s += tm["compile_s"]
             execute_s += tm["execute_s"]
             status.add(tm.get("aot_cache", "none"))
         return compile_s, execute_s, "+".join(sorted(status))

@@ -28,7 +28,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import traceback
 
 # runnable as `python benchmarks/run.py` from anywhere: the `benchmarks`
 # package lives at the repo root, not under src/
@@ -101,6 +100,9 @@ def main() -> None:
             s for s in ("grid", "packing", "search", "serving")
             if s not in args.only]
 
+    from repro.core.plan import enable_persistent_cache
+    enable_persistent_cache()
+
     from benchmarks import (determinism, dse_sweep, fig1_sim_time,
                             fig5_speedup, fig6_scheduler, fig7_ctas,
                             grid_sweep, kernels_bench, mesh_sweep, packing,
@@ -125,29 +127,24 @@ def main() -> None:
         "search": search_bench.run,
         "serving": serving.run,
     }
+    # a suite that raises ends the run with its traceback and a nonzero
+    # exit: no artifact is written for it, and no later suite runs
     rows = []
-    failed = False
     for name, fn in suites.items():
         if args.only and name not in args.only:
             continue
-        try:
-            suite_rows = fn()
-        except Exception:  # noqa: BLE001
-            failed = True
-            traceback.print_exc()
-            suite_rows = [{"name": name, "us_per_call": -1.0,
-                           "derived": "ERROR"}]
+        suite_rows = fn()
         save_bench(name, suite_rows)
         rows.extend(suite_rows)
     print("name,us_per_call,derived")
     for r in rows:
         print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
     if args.gate:
-        for msg in perf_gate():
+        fails = perf_gate()
+        for msg in fails:
             print(f"[gate] FAIL: {msg}")
-            failed = True
-    if failed:
-        sys.exit(1)
+        if fails:
+            sys.exit(1)
 
 
 if __name__ == "__main__":

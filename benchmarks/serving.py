@@ -24,7 +24,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import REPO, SIM_SCALE, save_json
+from benchmarks.common import REPO, SIM_SCALE, cpu_child_env, save_json
 
 SERVE_CYCLES = 1 << 15
 JOB_NAMES = ["mixed", "reduction_tree", "streaming_copy", "trace:vecadd",
@@ -46,7 +46,9 @@ def _subs() -> list:
 def _perjob_subprocess(sub: dict) -> float:
     """One job, one fresh process: build_job admission + solo simulate,
     paying interpreter start, jax import and compile — the pre-service
-    cost model.  Returns the wall-clock of the whole process."""
+    cost model.  The child runs on the CPU, and only when this process
+    does too (``cpu_child_env``).  Returns the wall-clock of the whole
+    process."""
     code = (
         "from repro.core.engine import simulate\n"
         "from repro.core.parallel import make_sm_runner\n"
@@ -58,7 +60,7 @@ def _perjob_subprocess(sub: dict) -> float:
         "    simulate(w, cfg, make_sm_runner(cfg, 'vmap'),\n"
         f"             plan=RunPlan(max_cycles={SERVE_CYCLES}))\n"
     )
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = cpu_child_env("the one-process-per-job baseline")
     t0 = time.perf_counter()
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
                          capture_output=True, text=True, timeout=1800)

@@ -197,8 +197,6 @@ def make_dist_sweep_runner(scfg: StaticConfig, mesh: Mesh,
     'sm'.  The initial state batch (placed by ``place_state``) is
     DONATED — in and out shardings match part-by-part, so the final
     state aliases the input buffers on every device."""
-    from jax.experimental.shard_map import shard_map
-
     scfg = static_part(scfg)
     run_lane = _make_lane_runner(scfg, mesh.shape[SM_AXIS], exchange,
                                  max_cycles, early_exit)
@@ -208,8 +206,8 @@ def make_dist_sweep_runner(scfg: StaticConfig, mesh: Mesh,
         return jax.vmap(run_lane, in_axes=(0, None, 0))(
             state, stacked, dyn_batch)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(specs, P(), P(CFG_AXIS)),
-                   out_specs=specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P(), P(CFG_AXIS)),
+                       out_specs=specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -223,8 +221,6 @@ def make_dist_grid_runner(scfg: StaticConfig, mesh: Mesh,
     (every device runs all W workloads for ITS config lanes); the config
     axis is sharded over 'cfg', the SM axis over 'sm'.  The (W, C)
     initial state batch is DONATED, same as the sweep runner."""
-    from jax.experimental.shard_map import shard_map
-
     scfg = static_part(scfg)
     run_lane = _make_lane_runner(scfg, mesh.shape[SM_AXIS], exchange,
                                  max_cycles, early_exit)
@@ -235,6 +231,6 @@ def make_dist_grid_runner(scfg: StaticConfig, mesh: Mesh,
         return jax.vmap(over_cfgs, in_axes=(0, 0, None))(
             state, stacked, dyn_batch)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(specs, P(), P(CFG_AXIS)),
-                   out_specs=specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(specs, P(), P(CFG_AXIS)),
+                       out_specs=specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))

@@ -229,23 +229,24 @@ def run_workload(state: dict, kernels: list, cfg: StaticConfig,
     return state
 
 
-def simulate(workload: Workload, cfg: GPUConfig, sm_runner,
-             max_cycles: int = None, jit: bool = True,
-             state_transform=None, plan=None) -> dict:
-    """Run all kernels of a workload; returns the final state.
+def build_simulation(workload: Workload, cfg: GPUConfig, sm_runner,
+                     plan=None, state_transform=None):
+    """Build the one traced program of a whole-workload run without
+    calling it: returns ``(run, scfg, dyn)``, and
+    ``run(init_state(scfg), dyn)`` is the final state.
 
-    The whole workload — state init, per-kernel reset, every kernel's
-    quantum loop — is one traced program (``lax.scan`` over the stacked
-    kernel axis), jitted once.
-
-    Execution knobs (max_cycles, early_exit, trace layout, cache dir)
-    come from ``plan=`` (core/plan.py:RunPlan); the bare ``max_cycles=``
-    keyword still works for one release via the deprecation shim."""
+    The whole workload — per-kernel reset, every kernel's quantum loop —
+    is one traced program (``lax.scan`` over the stacked kernel axis);
+    the kernel trace is closed over, the initial state and the typed
+    ``DynConfig`` are arguments, and it is jitted with the state donated.
+    Kept apart from ``simulate`` so callers can lower and compile it
+    separately from executing it (core/sweep.py:timed_call) or for a
+    described device."""
     from repro.core.batch import (check_workload_fits, concat_kernels,
                                   stack_kernels)
-    from repro.core.plan import resolve_plan
+    from repro.core.plan import RunPlan
 
-    plan = resolve_plan(plan, where="simulate", max_cycles=max_cycles)
+    plan = plan if plan is not None else RunPlan()
     plan.activate_caches()
     scfg, dyn = split_config(cfg)
     check_workload_fits(scfg, workload)
@@ -259,8 +260,23 @@ def simulate(workload: Workload, cfg: GPUConfig, sm_runner,
                                     state_transform,
                                     early_exit=plan.early_exit)
 
-    if jit:
-        # the freshly-built initial state is argument 0 and DONATED: the
-        # final state aliases its buffers instead of holding two copies
-        run = jax.jit(run, donate_argnums=(0,))
+    # the freshly-built initial state is argument 0 and DONATED: the
+    # final state aliases its buffers instead of holding two copies
+    return jax.jit(run, donate_argnums=(0,)), scfg, dyn
+
+
+def simulate(workload: Workload, cfg: GPUConfig, sm_runner,
+             max_cycles: int = None, state_transform=None,
+             plan=None) -> dict:
+    """Run all kernels of a workload; returns the final state
+    (``build_simulation``, then one call of it).
+
+    Execution knobs (max_cycles, early_exit, trace layout, cache dir)
+    come from ``plan=`` (core/plan.py:RunPlan); the bare ``max_cycles=``
+    keyword still works for one release via the deprecation shim."""
+    from repro.core.plan import resolve_plan
+
+    plan = resolve_plan(plan, where="simulate", max_cycles=max_cycles)
+    run, scfg, dyn = build_simulation(workload, cfg, sm_runner, plan,
+                                      state_transform)
     return run(init_state(scfg), dyn)

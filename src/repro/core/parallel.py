@@ -70,17 +70,15 @@ def make_sm_runner(cfg, mode: str = "vmap", mesh: Mesh = None):
             raise ValueError(
                 "mode='shard' needs mesh= with an 'sm' axis, e.g. "
                 "make_sm_runner(cfg, 'shard', make_host_mesh(n, 'sm'))")
-        from jax.experimental.shard_map import shard_map
-
         if len(mesh.axis_names) > 1:
             # Slice out a 1-D ('sm',) submesh: a shard_map whose specs
             # never mention some mesh axis mis-replicates across compiled
-            # loop iterations under check_rep=False (the claim is trusted,
-            # not enforced), so this runner — whose loop lives OUTSIDE the
-            # shard region in engine.quantum_step — must own every axis of
-            # the mesh it runs on.  Lane-parallel execution over a full
-            # 2-D ('cfg', 'sm') mesh is core/distribute.py's job, where
-            # the whole loop sits inside one shard_map.
+            # loop iterations under check_vma=False (the replication claim
+            # is trusted, not checked), so this runner — whose loop lives
+            # OUTSIDE the shard region in engine.quantum_step — must own
+            # every axis of the mesh it runs on.  Lane-parallel execution
+            # over a full 2-D ('cfg', 'sm') mesh is core/distribute.py's
+            # job, where the whole loop sits inside one shard_map.
             axis = mesh.axis_names.index("sm")
             devs = mesh.devices[tuple(
                 slice(None) if i == axis else 0
@@ -108,8 +106,8 @@ def make_sm_runner(cfg, mode: str = "vmap", mesh: Mesh = None):
             in_specs = tuple(spec_like(p, sm_spec) for p in parts) + (
                 spec_like(trace, rep), rep, spec_like(dyn, rep))
             out_specs = tuple(spec_like(p, sm_spec) for p in parts)
-            fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+            fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             return fn(warp, sm, req, stats_sm, trace, t0, dyn)
         return runner
 
@@ -202,8 +200,6 @@ def make_sharded_quantum(cfg: GPUConfig, mesh: Mesh,
     every inner cycle, emulating the paper's per-cycle OpenMP barrier;
     results are bit-identical, only communication frequency differs.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.shape["sm"]
     body = make_shard_body(cfg, n_dev, exchange)
 
@@ -224,8 +220,8 @@ def make_sharded_quantum(cfg: GPUConfig, mesh: Mesh,
                     spec_like(trace, rep),
                     spec_like(dyn, rep))
         out_specs = in_specs[:7]
-        fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         warp, sm, req, stats_sm, mem, ctrl, gstats = fn(
             state["warp"], state["sm"], state["req"], state["stats_sm"],
             state["mem"], state["ctrl"], state["stats"], trace, dyn)

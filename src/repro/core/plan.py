@@ -41,6 +41,7 @@ release — they build a RunPlan and warn once (DeprecationWarning).
 from __future__ import annotations
 
 import dataclasses
+import os
 import warnings
 from dataclasses import dataclass
 
@@ -244,42 +245,49 @@ def resolve_plan(plan, *, where: str = "sweep", mode=None, max_cycles=None,
 
 _persistent_cache_dir = None
 
+# the one fixed cache path used when JAX_COMPILATION_CACHE_DIR is unset:
+# inside the checkout (gitignored), never temp-, pid- or time-derived, so
+# every process of this checkout finds what an earlier one compiled
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-def enable_persistent_cache(cache_dir: str) -> str | None:
-    """Point jax's persistent compilation cache at ``cache_dir`` so
-    compiled programs survive the process — the ~17 s mesh-grid compile is
-    paid once per (StaticConfig, bucket shape), not once per run.
+
+def enable_persistent_cache(cache_dir: str | None = None) -> str:
+    """Point jax's persistent compilation cache at a directory so compiled
+    programs survive the process — the ~17 s mesh-grid compile is paid
+    once per (StaticConfig, bucket shape), not once per run.
+
+    Where the directory comes from, first match wins:
+      1. ``JAX_COMPILATION_CACHE_DIR`` — jax reads it itself, and no other
+         directory is set in code (``cache_dir`` is ignored);
+      2. ``cache_dir`` (``RunPlan.cache_dir``, ``--cache-dir``);
+      3. ``DEFAULT_CACHE_DIR``.
+    Entry points (``launch/*.py`` mains, ``chip_smoke.py``,
+    ``benchmarks/run.py``) call this with no argument or their
+    ``--cache-dir``; library calls wire only an explicit ``cache_dir``.
 
     Idempotent; re-wiring to a *different* directory raises (jax reads the
     config at compile time, silently splitting the cache would be worse).
-    Returns the active directory, or None when this jax build has no
-    compilation-cache config (the knobs are then best-effort skipped —
-    the in-process AOT cache in core/sweep.py still works)."""
+    Returns the active directory."""
     global _persistent_cache_dir
-    import os
-
     import jax
 
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    cache_dir = os.path.abspath(env_dir or cache_dir or DEFAULT_CACHE_DIR)
     if _persistent_cache_dir is not None:
-        if os.path.abspath(cache_dir) != _persistent_cache_dir:
+        if cache_dir != _persistent_cache_dir:
             raise ValueError(
                 f"persistent compile cache already wired to "
                 f"{_persistent_cache_dir}; refusing to re-wire to "
                 f"{cache_dir} mid-process")
         return _persistent_cache_dir
-    cache_dir = os.path.abspath(cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    try:
+    if not env_dir:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except AttributeError:          # ancient jax: no persistent cache at all
-        return None
     # cache every program, however small/fast — simulator programs are
     # worth re-using even when XLA thinks they compiled "quickly"
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _persistent_cache_dir = cache_dir
     return cache_dir

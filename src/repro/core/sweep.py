@@ -190,9 +190,8 @@ def clear_aot_cache() -> None:
 def timed_call(runner, *args, n_lanes: int = 1, cache_key=None) -> tuple:
     """Run a jitted program with the wall-clock split the run manifests
     record: AOT-lower + compile timed separately from execution, plus
-    lanes/sec of the executed program.  Falls back to a plain (fused)
-    call if AOT lowering is unavailable for the runner; the manifest then
-    reports compile_s=None and the execute time includes compilation.
+    lanes/sec of the executed program.  ``runner`` is always a
+    ``jax.jit``, so a lowering or compile error surfaces here as itself.
 
     With ``cache_key`` (``aot_cache_key``) the compiled executable is
     memoized on (key, argument shapes/dtypes): a warm call skips lower +
@@ -207,17 +206,12 @@ def timed_call(runner, *args, n_lanes: int = 1, cache_key=None) -> tuple:
             timings["compile_s"] = 0.0
             timings["aot_cache"] = "hit"
     if fn is None:
-        try:
-            t0 = time.perf_counter()
-            compiled = runner.lower(*args).compile()
-            timings["compile_s"] = round(time.perf_counter() - t0, 4)
-            fn = compiled
-            if cache_key is not None:
-                _AOT_CACHE[full_key] = compiled
-                timings["aot_cache"] = "miss"
-        except (AttributeError, TypeError, NotImplementedError):
-            timings["compile_s"] = None
-            fn = runner
+        t0 = time.perf_counter()
+        fn = runner.lower(*args).compile()
+        timings["compile_s"] = round(time.perf_counter() - t0, 4)
+        if cache_key is not None:
+            _AOT_CACHE[full_key] = fn
+            timings["aot_cache"] = "miss"
     t0 = time.perf_counter()
     out = jax.block_until_ready(fn(*args))
     timings["execute_s"] = round(time.perf_counter() - t0, 4)
@@ -468,11 +462,8 @@ def grid_sweep(workloads, cfgs, mode: str = None,
         for pos, w in enumerate(idxs):
             for c in range(nc):
                 stats[w][c] = S.finalize(take_grid_lane(bstate, pos, c))
-        if tm.get("compile_s") is None or timings["compile_s"] is None:
-            timings["compile_s"] = None
-        else:
-            timings["compile_s"] = round(
-                timings["compile_s"] + tm["compile_s"], 4)
+        timings["compile_s"] = round(
+            timings["compile_s"] + tm["compile_s"], 4)
         timings["execute_s"] = round(
             timings["execute_s"] + tm["execute_s"], 4)
         if "aot_cache" in tm:
@@ -602,11 +593,8 @@ def pair_sweep(pairs, plan: RunPlan = None,
         bucket_states.append((list(idxs), bstate))
         for pos, i in enumerate(idxs):      # duplicates past len(idxs) drop
             stats[i] = S.finalize(take_lane(bstate, pos))
-        if tm.get("compile_s") is None or timings["compile_s"] is None:
-            timings["compile_s"] = None
-        else:
-            timings["compile_s"] = round(
-                timings["compile_s"] + tm["compile_s"], 4)
+        timings["compile_s"] = round(
+            timings["compile_s"] + tm["compile_s"], 4)
         timings["execute_s"] = round(
             timings["execute_s"] + tm["execute_s"], 4)
         if "aot_cache" in tm:

@@ -57,7 +57,9 @@ def add_plan_args(ap: argparse.ArgumentParser) -> None:
     # -- compile caching ----------------------------------------------------
     ap.add_argument("--cache-dir", default="", metavar="DIR",
                     help="persistent XLA compilation cache directory — "
-                         "compiled programs survive the process "
+                         "compiled programs survive the process; "
+                         "JAX_COMPILATION_CACHE_DIR, when set, wins, and "
+                         "the default is <repo>/.jax_cache "
                          "(core/plan.py:enable_persistent_cache)")
     ap.add_argument("--no-aot-cache", action="store_true",
                     help="disable the in-process AOT executable cache "

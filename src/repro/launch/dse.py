@@ -40,6 +40,7 @@ from repro.core import stats as S
 from repro.core import telemetry as T
 from repro.core.engine import run_workload
 from repro.core.parallel import make_sm_runner
+from repro.core.plan import enable_persistent_cache
 from repro.core.sweep import sweep
 from repro.launch.cli import (add_plan_args, add_sample_args,
                               add_search_args, plan_from_args, profile_ctx)
@@ -183,6 +184,7 @@ def main(argv=None):
     add_search_args(ap)
     add_plan_args(ap)
     args = ap.parse_args(argv)
+    enable_persistent_cache(args.cache_dir or None)
     plan = plan_from_args(args)
 
     base = BASES[args.base]

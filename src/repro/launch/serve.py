@@ -4,7 +4,8 @@ The transport half of simulation-as-a-service (core/service.py holds the
 queue/admission/batch-former/result-router).  One warm process serves
 every client's jobs: submissions are continuously packed into pair lanes
 so unrelated requests share compiled programs, the in-process AOT
-executable cache, and (with --cache-dir) jax's persistent compile cache.
+executable cache, and jax's persistent compile cache (core/plan.py:
+enable_persistent_cache).
 
 Protocol (one JSON object per line, documented in benchmarks/README.md):
 
@@ -244,6 +245,8 @@ def selftest() -> int:
 
 def main(argv=None):
     args = _parse_args(argv)
+    from repro.core.plan import enable_persistent_cache
+    enable_persistent_cache(args.cache_dir or None)
     if args.selftest:
         raise SystemExit(selftest())
     plan = plan_from_args(args)

@@ -50,7 +50,7 @@ from repro.core import stats as S
 from repro.core import telemetry as T
 from repro.core.engine import simulate
 from repro.core.parallel import make_sm_runner
-from repro.core.plan import RunPlan
+from repro.core.plan import RunPlan, enable_persistent_cache
 from repro.core.sweep import grid_sweep
 from repro.launch.cli import (add_plan_args, add_sample_args, plan_from_args,
                               profile_ctx)
@@ -205,6 +205,7 @@ def main(argv=None):
     add_sample_args(ap, when="--grid")
     add_plan_args(ap)
     args = ap.parse_args(argv)
+    enable_persistent_cache(args.cache_dir or None)
 
     if (args.sample_lat or args.sample_disp) and not args.grid:
         raise SystemExit("--sample-lat/--sample-disp shape the config grid "

@@ -169,10 +169,13 @@ def mem_phase(req: dict, mem: dict, stats: dict, t0, cfg: StaticConfig,
     dram_busy = mem["dram_busy"].at[ch_c].max(jnp.where(o_sel2, finish2, 0))
     seg_last = jnp.concatenate([o_ch[1:] != o_ch[:-1],
                                 jnp.ones((1,), jnp.bool_)])
+    # open row per channel = row of the channel's last served request; only
+    # that row writes (unique indices), every other row goes out of range
+    # and is dropped, as in the L2 insert above
     last_sel = seg_last & o_sel2
     dram_row = mem["dram_row"].at[jnp.where(last_sel, ch_c,
-                                            cfg.dram_channels - 1)].set(
-        jnp.where(last_sel, o_row, mem["dram_row"][cfg.dram_channels - 1]))
+                                            cfg.dram_channels)].set(
+        o_row, mode="drop")
 
     stats = dict(stats,
                  dram_req=stats["dram_req"] + jnp.sum(o_sel2,

@@ -10,6 +10,7 @@ lower+compile entirely, and the legacy flat kwargs still work (warn once).
 """
 import dataclasses
 import json
+import os
 import warnings
 
 import jax
@@ -92,12 +93,30 @@ def test_runplan_describe_is_json_safe():
 def test_persistent_cache_idempotent_and_rewire_refused(tmp_path,
                                                         monkeypatch):
     monkeypatch.setattr(plan_mod, "_persistent_cache_dir", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = enable_persistent_cache(str(tmp_path / "cache"))
-    if d is None:            # jax build without a compilation-cache config
-        pytest.skip("no persistent compilation cache in this jax")
+    assert d == str(tmp_path / "cache")
     assert enable_persistent_cache(str(tmp_path / "cache")) == d
     with pytest.raises(ValueError, match="refusing to re-wire"):
         enable_persistent_cache(str(tmp_path / "elsewhere"))
+
+
+def test_persistent_cache_env_dir_wins_and_default_is_fixed(tmp_path,
+                                                            monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache: an explicit
+    cache_dir does not override it and no other directory is set in code.
+    Unset, the entry-point default is one fixed path in the checkout."""
+    monkeypatch.setattr(plan_mod, "_persistent_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_persistent_cache(str(tmp_path / "plan")) == \
+        str(tmp_path / "env")
+    assert enable_persistent_cache() == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "plan").exists()
+    assert plan_mod.DEFAULT_CACHE_DIR == os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
 
 
 # ---------------------------------------------------------------------------
