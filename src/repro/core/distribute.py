@@ -38,7 +38,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import telemetry
-from repro.core.engine import run_workload_stacked
+from repro.core.engine import (LOOP_CONTROL, mark_entry_converged,
+                               run_workload_stacked)
 from repro.core.parallel import make_shard_body
 from repro.sim.config import StaticConfig, static_part
 
@@ -129,8 +130,9 @@ def make_dist_kernel_runner(scfg: StaticConfig, n_sm_dev: int,
 
     def kernel_runner(st, packed, dyn):
         def cond(s):
-            return (s["ctrl"]["done_cycle"] < 0) & \
-                (s["ctrl"]["cycle"] < max_cycles)
+            with jax.named_scope(LOOP_CONTROL):
+                return (s["ctrl"]["done_cycle"] < 0) & \
+                    (s["ctrl"]["cycle"] < max_cycles)
 
         def step(s):
             warp, sm, req, stats_sm, mem, ctrl, gstats = body(
@@ -142,8 +144,9 @@ def make_dist_kernel_runner(scfg: StaticConfig, n_sm_dev: int,
                 # per-SM arrays here are this device's shard — the counter
                 # sums psum over 'sm' so the replicated buffer row holds
                 # full-machine totals, bit-identical on every device
-                out["telem"] = telemetry.quantum_update(
-                    s["telem"], out, packed, scfg, axis_name=SM_AXIS)
+                with jax.named_scope(LOOP_CONTROL):
+                    out["telem"] = telemetry.quantum_update(
+                        s["telem"], out, packed, scfg, axis_name=SM_AXIS)
             return out
 
         if early_exit:
@@ -151,12 +154,13 @@ def make_dist_kernel_runner(scfg: StaticConfig, n_sm_dev: int,
             # a while_loop cond); warp/req are local shards, so the live/
             # busy counts psum over 'sm' — every device agrees, and an
             # empty padding kernel skips its quantum (all-gathers included)
-            from repro.core.engine import mark_entry_converged
-            st = mark_entry_converged(st, packed, axis_name=SM_AXIS)
+            with jax.named_scope(LOOP_CONTROL):
+                st = mark_entry_converged(st, packed, axis_name=SM_AXIS)
         st = jax.lax.while_loop(cond, step, st)
         if telem_on:
-            st = dict(st, telem=telemetry.sample(
-                st["telem"], st, scfg, axis_name=SM_AXIS, force=True))
+            with jax.named_scope(LOOP_CONTROL):
+                st = dict(st, telem=telemetry.sample(
+                    st["telem"], st, scfg, axis_name=SM_AXIS, force=True))
         return st
 
     return kernel_runner

@@ -17,6 +17,12 @@ separately.  All timing numerics — scalar latencies AND the per-class
 whole engine over a batch of dynamic configs (one design-space-exploration
 lane per config, ~20+ sweepable entries each).
 
+Phase scopes: every execution mode runs the quantum's phases under the
+``jax.named_scope`` names in ``PHASES``, so each instruction of the
+compiled program carries its phase in the ``op_name`` of its metadata
+and a profile splits device time by phase.  Scopes change metadata
+only: the compiled program is otherwise the same.
+
 Kernel threading: a workload's kernels are padded + stacked
 (core/batch.py) and run by a ``lax.scan`` over the kernel axis
 (``run_workload_stacked``) — the whole workload is ONE traced program, so
@@ -34,6 +40,13 @@ from repro.sim.cta import cta_issue
 from repro.sim.memsys import mem_phase
 from repro.sim.state import init_state, reset_for_kernel
 from repro.sim.trace import Workload
+
+# the quantum loop's phases, as named scopes in the compiled program's
+# op_name metadata: memory phase, CTA dispatch, the SM runner, and the
+# loop's own bookkeeping (convergence, clock, per-kernel reset, cycle and
+# timeout accounting, telemetry)
+PHASES = MEM_PHASE, CTA_ISSUE, SM_PHASE, LOOP_CONTROL = (
+    "sim.mem_phase", "sim.cta_issue", "sim.sm_phase", "sim.loop_control")
 
 
 def converged(ctrl: dict, warp: dict, req: dict, trace: dict,
@@ -77,25 +90,30 @@ def mark_entry_converged(state: dict, trace: dict, axis_name=None) -> dict:
 def quantum_step(state: dict, trace: dict, cfg: StaticConfig,
                  dyn: DynConfig, sm_runner):
     t0 = state["ctrl"]["cycle"]
-    req, mem, gstats = mem_phase(state["req"], state["mem"], state["stats"],
-                                 t0, cfg, dyn,
-                                 sm_ids=state["ctrl"]["sm_ids"])
-    warp, ctrl, gstats = cta_issue(state["warp"], dict(state["ctrl"]),
-                                   gstats, trace, cfg)
-    warp, sm, req, stats_sm = sm_runner(warp, state["sm"], req,
-                                        state["stats_sm"], trace, t0, dyn)
-    cycle_end = t0 + cfg.quantum
-    done = converged(ctrl, warp, req, trace)
-    done_cycle = jnp.where((ctrl["done_cycle"] < 0) & done, cycle_end,
-                           ctrl["done_cycle"])
-    ctrl = dict(ctrl, cycle=cycle_end, done_cycle=done_cycle)
-    out = {"warp": warp, "sm": sm, "req": req, "mem": mem, "ctrl": ctrl,
-           "stats_sm": stats_sm, "stats": gstats}
-    # opt-in counter timeline: statically gated, so the compiled program
-    # is unchanged when telemetry is off (core/telemetry.py)
-    if telemetry.enabled(cfg):
-        out["telem"] = telemetry.quantum_update(state["telem"], out,
-                                                trace, cfg)
+    with jax.named_scope(MEM_PHASE):
+        req, mem, gstats = mem_phase(state["req"], state["mem"],
+                                     state["stats"], t0, cfg, dyn,
+                                     sm_ids=state["ctrl"]["sm_ids"])
+    with jax.named_scope(CTA_ISSUE):
+        warp, ctrl, gstats = cta_issue(state["warp"], dict(state["ctrl"]),
+                                       gstats, trace, cfg)
+    with jax.named_scope(SM_PHASE):
+        warp, sm, req, stats_sm = sm_runner(warp, state["sm"], req,
+                                            state["stats_sm"], trace, t0,
+                                            dyn)
+    with jax.named_scope(LOOP_CONTROL):
+        cycle_end = t0 + cfg.quantum
+        done = converged(ctrl, warp, req, trace)
+        done_cycle = jnp.where((ctrl["done_cycle"] < 0) & done, cycle_end,
+                               ctrl["done_cycle"])
+        ctrl = dict(ctrl, cycle=cycle_end, done_cycle=done_cycle)
+        out = {"warp": warp, "sm": sm, "req": req, "mem": mem, "ctrl": ctrl,
+               "stats_sm": stats_sm, "stats": gstats}
+        # opt-in counter timeline: statically gated, so the compiled
+        # program is unchanged when telemetry is off (core/telemetry.py)
+        if telemetry.enabled(cfg):
+            out["telem"] = telemetry.quantum_update(state["telem"], out,
+                                                    trace, cfg)
     return out
 
 
@@ -103,20 +121,23 @@ def run_kernel(state: dict, trace: dict, cfg: StaticConfig,
                dyn: DynConfig, sm_runner, max_cycles: int = 1 << 20,
                early_exit: bool = True):
     def cond(st):
-        return (st["ctrl"]["done_cycle"] < 0) & \
-            (st["ctrl"]["cycle"] < max_cycles)
+        with jax.named_scope(LOOP_CONTROL):
+            return (st["ctrl"]["done_cycle"] < 0) & \
+                (st["ctrl"]["cycle"] < max_cycles)
 
     def body(st):
         return quantum_step(st, trace, cfg, dyn, sm_runner)
 
     if early_exit:
-        state = mark_entry_converged(state, trace)
+        with jax.named_scope(LOOP_CONTROL):
+            state = mark_entry_converged(state, trace)
     state = jax.lax.while_loop(cond, body, state)
     # force a final snapshot per kernel so the last written timeline row
     # always equals the final cumulative counters (core/telemetry.py)
     if telemetry.enabled(cfg):
-        state = dict(state, telem=telemetry.sample(
-            state["telem"], state, cfg, force=True))
+        with jax.named_scope(LOOP_CONTROL):
+            state = dict(state, telem=telemetry.sample(
+                state["telem"], state, cfg, force=True))
     return state
 
 
@@ -173,21 +194,23 @@ def run_workload_stacked(state: dict, stacked: dict, cfg: StaticConfig,
 
     def body(carry, scanned):
         prev, total, timeouts = carry
-        packed = dict(flat, **scanned) if ragged else scanned
-        st = reset_for_kernel(prev, cfg)
-        if state_transform is not None:
-            st = state_transform(st)
+        with jax.named_scope(LOOP_CONTROL):
+            packed = dict(flat, **scanned) if ragged else scanned
+            st = reset_for_kernel(prev, cfg)
+            if state_transform is not None:
+                st = state_transform(st)
         if kernel_runner is None:
             st = run_kernel(st, packed, cfg, dyn, sm_runner, max_cycles,
                             early_exit)
         else:
             st = kernel_runner(st, packed, dyn)
-        empty = packed["n_ctas"] == 0
-        total = total + jnp.where(empty, 0, kernel_cycles(st["ctrl"]))
-        timeouts = timeouts + jnp.where(
-            ~empty & (st["ctrl"]["done_cycle"] < 0), 1, 0)
-        nxt = jax.tree_util.tree_map(
-            lambda old, new: jnp.where(empty, old, new), prev, st)
+        with jax.named_scope(LOOP_CONTROL):
+            empty = packed["n_ctas"] == 0
+            total = total + jnp.where(empty, 0, kernel_cycles(st["ctrl"]))
+            timeouts = timeouts + jnp.where(
+                ~empty & (st["ctrl"]["done_cycle"] < 0), 1, 0)
+            nxt = jax.tree_util.tree_map(
+                lambda old, new: jnp.where(empty, old, new), prev, st)
         return (nxt, total, timeouts), None
 
     (state, total, timeouts), _ = jax.lax.scan(

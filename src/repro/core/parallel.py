@@ -24,6 +24,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import telemetry
+from repro.core.engine import (CTA_ISSUE, LOOP_CONTROL, MEM_PHASE, SM_PHASE,
+                               converged, mark_entry_converged)
 from repro.sim.config import GPUConfig, split_config, static_part
 from repro.sim.cta import cta_issue
 from repro.sim.memsys import mem_phase
@@ -142,16 +144,31 @@ def make_shard_body(cfg, n_dev: int, exchange: str = "window"):
             lambda x: jax.lax.all_gather(x, "sm", axis=0, tiled=True), req)
         warp_f = jax.tree_util.tree_map(
             lambda x: jax.lax.all_gather(x, "sm", axis=0, tiled=True), warp)
-        req_f, mem, gstats = mem_phase(req_f, mem, gstats, t0, scfg, dyn,
-                                       sm_ids=ctrl["sm_ids"])
-        warp_f, ctrl, gstats = cta_issue(warp_f, dict(ctrl), gstats, trace,
-                                         scfg)
+        with jax.named_scope(MEM_PHASE):
+            req_f, mem, gstats = mem_phase(req_f, mem, gstats, t0, scfg,
+                                           dyn, sm_ids=ctrl["sm_ids"])
+        with jax.named_scope(CTA_ISSUE):
+            warp_f, ctrl, gstats = cta_issue(warp_f, dict(ctrl), gstats,
+                                             trace, scfg)
         i = jax.lax.axis_index("sm")
         take = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
             x, i * chunk, chunk, axis=0)
         req_l = jax.tree_util.tree_map(take, req_f)
         warp_l = jax.tree_util.tree_map(take, warp_f)
         # --- parallel region: my SM shard ------------------------------
+        with jax.named_scope(SM_PHASE):
+            warp_l, sm, req_l, stats_sm = sm_shard(warp_l, sm, req_l,
+                                                   stats_sm, trace, t0, dyn)
+        # --- done detection (replicated) --------------------------------
+        with jax.named_scope(LOOP_CONTROL):
+            cycle_end = t0 + scfg.quantum
+            done = converged(ctrl, warp_l, req_l, trace, axis_name="sm")
+            done_cycle = jnp.where((ctrl["done_cycle"] < 0) & done,
+                                   cycle_end, ctrl["done_cycle"])
+            ctrl = dict(ctrl, cycle=cycle_end, done_cycle=done_cycle)
+        return warp_l, sm, req_l, stats_sm, mem, ctrl, gstats
+
+    def sm_shard(warp_l, sm, req_l, stats_sm, trace, t0, dyn):
         if exchange == "cycle":
             # emulate a per-cycle barrier: gather the table every cycle
             from repro.sim.smcore import sm_cycle_single
@@ -170,20 +187,11 @@ def make_shard_body(cfg, n_dev: int, exchange: str = "window"):
             warp_l, sm, req_l, stats_sm, _ = jax.lax.fori_loop(
                 0, scfg.quantum, cyc,
                 (warp_l, sm, req_l, stats_sm, jnp.zeros((), jnp.int32)))
-        else:
-            warp_l, sm, req_l, stats_sm = jax.vmap(
-                lambda w, s, r, st: sm_quantum_single(w, s, r, st, trace, t0,
-                                                      scfg, dyn))(
-                warp_l, sm, req_l, stats_sm)
-        # --- done detection (replicated) --------------------------------
-        from repro.core.engine import converged
-
-        cycle_end = t0 + scfg.quantum
-        done = converged(ctrl, warp_l, req_l, trace, axis_name="sm")
-        done_cycle = jnp.where((ctrl["done_cycle"] < 0) & done, cycle_end,
-                               ctrl["done_cycle"])
-        ctrl = dict(ctrl, cycle=cycle_end, done_cycle=done_cycle)
-        return warp_l, sm, req_l, stats_sm, mem, ctrl, gstats
+            return warp_l, sm, req_l, stats_sm
+        return jax.vmap(
+            lambda w, s, r, st: sm_quantum_single(w, s, r, st, trace, t0,
+                                                  scfg, dyn))(
+            warp_l, sm, req_l, stats_sm)
 
     return body
 
@@ -230,8 +238,9 @@ def make_sharded_quantum(cfg: GPUConfig, mesh: Mesh,
         # telemetry runs OUTSIDE the shard region, where the out_specs
         # have reassembled the full per-SM arrays — no collectives needed
         if "telem" in state:
-            out["telem"] = telemetry.quantum_update(
-                state["telem"], out, trace, static_part(cfg))
+            with jax.named_scope(LOOP_CONTROL):
+                out["telem"] = telemetry.quantum_update(
+                    state["telem"], out, trace, static_part(cfg))
         return out
 
     return sharded_step
@@ -245,8 +254,9 @@ def run_kernel_sharded(state, trace, cfg: GPUConfig, mesh: Mesh,
     step = make_sharded_quantum(cfg, mesh, exchange)
 
     def cond(st):
-        return (st["ctrl"]["done_cycle"] < 0) & \
-            (st["ctrl"]["cycle"] < max_cycles)
+        with jax.named_scope(LOOP_CONTROL):
+            return (st["ctrl"]["done_cycle"] < 0) & \
+                (st["ctrl"]["cycle"] < max_cycles)
 
     def body(st):
         return step(st, trace, dyn)
@@ -254,12 +264,13 @@ def run_kernel_sharded(state, trace, cfg: GPUConfig, mesh: Mesh,
     if early_exit:
         # state here holds the FULL per-SM arrays (out_specs reassemble
         # outside the shard region), so no collective is needed
-        from repro.core.engine import mark_entry_converged
-        state = mark_entry_converged(state, trace)
+        with jax.named_scope(LOOP_CONTROL):
+            state = mark_entry_converged(state, trace)
     state = jax.lax.while_loop(cond, body, state)
     if "telem" in state:
-        state = dict(state, telem=telemetry.sample(
-            state["telem"], state, static_part(cfg), force=True))
+        with jax.named_scope(LOOP_CONTROL):
+            state = dict(state, telem=telemetry.sample(
+                state["telem"], state, static_part(cfg), force=True))
     return state
 
 

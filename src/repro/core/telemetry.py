@@ -26,6 +26,16 @@ the replicated buffer holds full-machine totals.  With telemetry disabled
 (the default) the state pytree and the compiled program are bit-for-bit
 unchanged — the determinism golden needs no regeneration.
 
+**Compile counters** — one process-wide counter set of compile work,
+filled from JAX's own monitoring events through listeners registered
+once, when this module is imported (the engine imports it before it
+builds a program, so the counters see every compile of a simulation):
+how many times a function was traced to a jaxpr, lowered to an MLIR
+module, and compiled by the backend (or loaded from the persistent
+cache), the seconds of each stage, and the persistent cache's hits,
+misses and retrieval seconds.  ``compile_counters()`` is a snapshot and
+``compile_delta(a, b)`` the work between two snapshots.
+
 **Run manifests** — every launcher/bench run can write a structured JSON
 manifest under ``experiments/runs/``: git sha, StaticConfig hash, host
 context (hostname, device kind/count, XLA_FLAGS), mesh shape, the
@@ -38,6 +48,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import threading
 import time
 from dataclasses import asdict
 
@@ -166,6 +177,93 @@ def check_final_sample(state: dict, finalized: dict) -> list:
     last = tl[-1]
     return [name for name in FINAL_MATCH
             if int(last[COUNTERS.index(name)]) != int(finalized[name])]
+
+
+# ---------------------------------------------------------------------------
+# compile counters
+# ---------------------------------------------------------------------------
+
+# the stages of one compile, as JAX times them: tracing to a jaxpr,
+# lowering to an MLIR module, and the backend compile, which spans the
+# persistent-cache lookup and so holds a cache hit's retrieval too
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _CompileCounters:
+    """The process's compile work as JAX reports it: each stage's spans
+    (wall-clock start, end), cache hits, misses and retrievals."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.spans = {stage: [] for stage in COMPILE_STAGES.values()}
+        self.counts = dict.fromkeys(
+            ("cache_hits", "cache_misses", "cache_retrievals"), 0)
+        self.retrieval_s = 0.0
+
+    def on_span(self, event: str, start: float, end: float, **_):
+        stage = COMPILE_STAGES.get(event)
+        if stage is not None:
+            with self.lock:
+                self.spans[stage].append((start, end))
+
+    def on_event(self, event: str, **_):
+        name = CACHE_EVENTS.get(event)
+        if name is not None:
+            with self.lock:
+                self.counts[name] += 1
+
+    def on_duration(self, event: str, secs: float, **_):
+        if event == CACHE_RETRIEVAL:
+            with self.lock:
+                self.counts["cache_retrievals"] += 1
+                self.retrieval_s += secs
+
+
+# one set per process, as JAX's listeners are, counting from this import
+_COMPILES = _CompileCounters()
+jax.monitoring.register_event_time_span_listener(_COMPILES.on_span)
+jax.monitoring.register_event_listener(_COMPILES.on_event)
+jax.monitoring.register_event_duration_secs_listener(_COMPILES.on_duration)
+
+
+def _covered(spans) -> float:
+    """Seconds covered by the union of (start, end) spans: a jit traced
+    inside another's trace nests in it and is not counted twice."""
+    total, end = 0.0, float("-inf")
+    for s, e in sorted(spans):
+        if e > end:
+            total += e - max(s, end)
+            end = e
+    return total
+
+
+def compile_counters() -> dict:
+    """Snapshot of the process's compile work since this module's import:
+    ``<stage>_n`` events and ``<stage>_s`` seconds for each stage of
+    ``COMPILE_STAGES``, ``cache_hits``, ``cache_misses``,
+    ``cache_retrievals`` and ``cache_retrieval_s``."""
+    c = _COMPILES
+    with c.lock:
+        spans = {k: list(v) for k, v in c.spans.items()}
+        out = dict(c.counts, cache_retrieval_s=c.retrieval_s)
+    for stage, sp in spans.items():
+        out[f"{stage}_n"] = len(sp)
+        out[f"{stage}_s"] = _covered(sp)
+    return out
+
+
+def compile_delta(before: dict, after: dict) -> dict:
+    """The compile work between two ``compile_counters`` snapshots."""
+    return {k: after[k] - before[k] for k in after}
 
 
 # ---------------------------------------------------------------------------

@@ -73,7 +73,11 @@ def add_plan_args(ap: argparse.ArgumentParser) -> None:
                     help="sampling cadence in quanta (default 1)")
     ap.add_argument("--profile", default="", metavar="DIR",
                     help="capture a jax.profiler (XLA-level) trace of the "
-                         "run into DIR, alongside the manifest")
+                         "run into DIR, alongside the manifest; every op of "
+                         "the quantum loop carries its phase scope "
+                         "(sim.mem_phase, sim.cta_issue, sim.sm_phase, "
+                         "sim.loop_control; core/engine.py:PHASES) in its "
+                         "op_name")
     ap.add_argument("--no-manifest", action="store_true",
                     help="skip writing the run manifest JSON under "
                          "experiments/runs/")

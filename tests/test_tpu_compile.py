@@ -13,6 +13,7 @@ compilation cache is off around these compiles: an executable compiled
 for a described chip is written to it but cannot be read back here.
 """
 import os
+import re
 from functools import partial
 
 import jax
@@ -24,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import distribute
 from repro.core.batch import stack_workloads
-from repro.core.engine import build_simulation
+from repro.core.engine import PHASES, build_simulation
 from repro.core.parallel import make_sm_runner
 from repro.core.plan import RunPlan
 from repro.core.sweep import batched_init, make_pair_runner, stack_dyn
@@ -70,7 +71,8 @@ def shapes_of(tree, sharding):
 
 def test_simulate_80sm_compiles_for_v5e(one_chip):
     """The long-run program (hotspot at scale 1.0 on the 80-SM model, the
-    path of repro.launch.simulate) compiles for one v5e chip and fits."""
+    path of repro.launch.simulate) compiles for one v5e chip and fits, and
+    every phase scope survives the TPU compiler in its ops' metadata."""
     w = make_workload("hotspot", scale=1.0)
     run, scfg, dyn = build_simulation(
         w, RTX3080TI, make_sm_runner(RTX3080TI, "vmap"),
@@ -80,6 +82,8 @@ def test_simulate_80sm_compiles_for_v5e(one_chip):
                          shapes_of(dyn, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert set(re.findall(r"(?<![\w.])sim\.\w+", compiled.as_text())) == \
+        set(PHASES)
 
 
 def test_pair_runner_compiles_for_v5e(one_chip):
