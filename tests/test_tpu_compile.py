@@ -17,6 +17,7 @@ import re
 from functools import partial
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,7 +31,8 @@ from repro.core.parallel import make_sm_runner
 from repro.core.plan import RunPlan
 from repro.core.sweep import batched_init, make_pair_runner, stack_dyn
 from repro.launch.dse import default_grid
-from repro.sim.config import RTX3080TI
+from repro.sim.config import RTX3080TI, split_config
+from repro.sim.smcore import sm_cycle_single
 from repro.sim.state import init_state
 from repro.sim.workloads import zoo_workload
 from repro.workloads import make_workload
@@ -84,6 +86,122 @@ def test_simulate_80sm_compiles_for_v5e(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     assert set(re.findall(r"(?<![\w.])sim\.\w+", compiled.as_text())) == \
         set(PHASES)
+
+
+# an HLO computation's header line, and an instruction's opcode: the first
+# lower-case word followed by "(" after the "=" (shapes and layouts hold
+# no such word)
+HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%(\S+)\s.*\{\s*$")
+HLO_OPCODE = re.compile(r"^\s+(?:ROOT\s+)?%[^\s=]+\s*=\s*.*?\s([a-z][\w-]*)\(")
+HLO_CALLS = re.compile(r"\b(?:calls|body|condition|to_apply)=%([^\s,]+)")
+HLO_BODY = re.compile(r"\bbody=%([^\s,]+)")
+
+
+def hlo_computations(text):
+    """{computation name: [its instruction lines]} of HLO text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = HLO_COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and HLO_OPCODE.match(line):
+            cur.append(line)
+    return comps
+
+
+def sm_cycle_body(comps):
+    """The SM-cycle loop: the innermost ``while`` body whose instructions
+    carry the ``sim.sm_phase`` scope."""
+    bodies = {HLO_BODY.search(line).group(1)
+              for lines in comps.values() for line in lines
+              if HLO_OPCODE.match(line).group(1) == "while"}
+    sm = {b for b in bodies if any("sim.sm_phase" in line
+                                   for line in comps[b])}
+    inner = [b for b in sm if not any(
+        HLO_OPCODE.match(line).group(1) == "while"
+        and HLO_BODY.search(line).group(1) in sm for line in comps[b])]
+    assert len(inner) == 1, inner
+    return comps[inner[0]]
+
+
+def uses(comps, line, opcode, seen=None):
+    """Whether an instruction is ``opcode`` or calls a computation that
+    holds one (a fusion holding a scatter is a scatter kernel)."""
+    seen = set() if seen is None else seen
+    if HLO_OPCODE.match(line).group(1) == opcode:
+        return True
+    for c in HLO_CALLS.findall(line):
+        if c not in seen:
+            seen.add(c)
+            if any(uses(comps, inner, opcode, seen) for inner in comps[c]):
+                return True
+    return False
+
+
+def test_sm_cycle_compiles_without_scatters_for_v5e(one_chip):
+    """The SM cycle addresses SM-local state by one-hot masks, so the
+    SM-cycle loop of the 80-SM program, compiled for one v5e, holds no
+    scatter kernel, and gathers only for the instruction fetch."""
+    w = make_workload("lavaMD", scale=0.25)
+    run, scfg, dyn = build_simulation(
+        w, RTX3080TI, make_sm_runner(RTX3080TI, "vmap"),
+        RunPlan(max_cycles=1 << 17))
+    state = jax.eval_shape(partial(init_state, scfg))
+    comps = hlo_computations(run.lower(shapes_of(state, one_chip),
+                                       shapes_of(dyn, one_chip))
+                             .compile().as_text())
+    body = sm_cycle_body(comps)
+    scatters = [line for line in body if uses(comps, line, "scatter")]
+    gathers = [line for line in body if uses(comps, line, "gather")]
+    assert not scatters, scatters[:3]
+    assert len(gathers) <= 8, len(gathers)
+
+
+def test_sm_cycle_jaxpr_gathers_only_from_the_trace():
+    """The CPU twin of the test above, on the jaxpr of the SM cycle
+    vmapped over the 80 SMs: no scatter of any kind, and one gather, the
+    instruction fetch, from a table computed from the trace's
+    instruction tables and constants alone — no SM state."""
+    scfg, dyn = split_config(RTX3080TI)
+    state = jax.eval_shape(partial(init_state, scfg))
+    trace = make_workload("lavaMD", scale=0.25).kernels[0].pack()
+    names = sorted(trace)
+
+    def cycle(trace, warp, sm, req, stats):
+        return jax.vmap(lambda w, s, r, st: sm_cycle_single(
+            w, s, r, st, trace, jnp.int32(7), scfg, dyn))(
+            warp, sm, req, stats)
+
+    closed = jax.make_jaxpr(cycle)(trace, state["warp"], state["sm"],
+                                   state["req"], state["stats_sm"])
+    tables = [v for v, n in zip(closed.jaxpr.invars, names)
+              if n in ("ops", "dep", "addr_mode", "addr_param")]
+    gathers = []
+
+    def walk(jaxpr, tables):
+        """``tables``: the variables of ``jaxpr`` computed from the trace
+        tables and constants alone, grown equation by equation (by
+        identity: literals are not hashable)."""
+        def table(x):
+            return any(x is v for v in tables)
+
+        for e in jaxpr.eqns:
+            name = e.primitive.name
+            assert not name.startswith("scatter"), name
+            if name == "gather":
+                assert table(e.invars[0]), e
+                gathers.append(e)
+            for p in e.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns"):
+                    walk(sub, [i for i, o in zip(sub.invars, e.invars)
+                               if table(o)])
+            if all(table(x) for x in e.invars
+                   if isinstance(x, jex_core.Var)):
+                tables = tables + list(e.outvars)
+
+    walk(closed.jaxpr, tables)
+    assert len(gathers) == 1, len(gathers)
 
 
 def test_pair_runner_compiles_for_v5e(one_chip):
