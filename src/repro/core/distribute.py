@@ -146,7 +146,7 @@ def make_dist_kernel_runner(scfg: StaticConfig, n_sm_dev: int,
                 # full-machine totals, bit-identical on every device
                 with jax.named_scope(LOOP_CONTROL):
                     out["telem"] = telemetry.quantum_update(
-                        s["telem"], out, packed, scfg, axis_name=SM_AXIS)
+                        s, out, packed, scfg, axis_name=SM_AXIS)
             return out
 
         if early_exit:

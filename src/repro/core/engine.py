@@ -112,7 +112,7 @@ def quantum_step(state: dict, trace: dict, cfg: StaticConfig,
         # opt-in counter timeline: statically gated, so the compiled
         # program is unchanged when telemetry is off (core/telemetry.py)
         if telemetry.enabled(cfg):
-            out["telem"] = telemetry.quantum_update(state["telem"], out,
+            out["telem"] = telemetry.quantum_update(state, out,
                                                     trace, cfg)
     return out
 

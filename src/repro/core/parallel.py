@@ -240,7 +240,7 @@ def make_sharded_quantum(cfg: GPUConfig, mesh: Mesh,
         if "telem" in state:
             with jax.named_scope(LOOP_CONTROL):
                 out["telem"] = telemetry.quantum_update(
-                    state["telem"], out, trace, static_part(cfg))
+                    state, out, trace, static_part(cfg))
         return out
 
     return sharded_step

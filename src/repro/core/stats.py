@@ -33,11 +33,13 @@ def finalize(state: dict) -> dict:
     ipc = out["issued"] / max(out["cycles"], 1)
     out["ipc"] = round(ipc, 4)
     # opt-in telemetry (core/telemetry.py): cumulative lockstep-waste and
-    # the number of timeline samples taken.  Harness metadata like the
-    # timeout counters — NOT part of comparable(), so telemetry-on runs
-    # stay bit-identical to telemetry-off runs on the comparable subset.
+    # memory-phase trips, and the number of timeline samples taken.
+    # Harness metadata like the timeout counters — NOT part of
+    # comparable(), so telemetry-on runs stay bit-identical to
+    # telemetry-off runs on the comparable subset.
     if "telem" in state:
         out["lockstep_waste"] = int(np.asarray(state["telem"]["waste"]))
+        out["mem_chunks"] = int(np.asarray(state["telem"]["chunks"]))
         out["telemetry_samples"] = int(np.asarray(state["telem"]["idx"]))
     return out
 

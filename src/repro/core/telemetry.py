@@ -5,18 +5,23 @@ Two halves, one module:
 **In-trace timelines** — when ``StaticConfig.telemetry_samples > 0`` the
 state pytree (sim/state.py:init_state) grows a ``telem`` part: a
 preallocated ``(telemetry_samples, N_COUNTERS)`` int32 ring-free buffer, a
-write index, and a cumulative *lockstep-waste* accumulator.  Every
-``telemetry_every``-th quantum the engine snapshots the cumulative per-SM
-counters (summed over SMs), the global memory-system counters, the
-instantaneous live-warp count and the waste accumulator into the next
-buffer row (``sample``); the end of every kernel forces a snapshot, so the
-LAST written row always equals the run's final cumulative counters —
+write index, and cumulative *lockstep-waste* and *memory-chunk*
+accumulators.  Every ``telemetry_every``-th quantum the engine snapshots
+the cumulative per-SM counters (summed over SMs), the global memory-system
+counters, the instantaneous live-warp count and the two accumulators into
+the next buffer row (``sample``); the end of every kernel forces a
+snapshot, so the LAST written row always equals the run's final
+cumulative counters —
 the invariant tests/test_telemetry.py locks against ``stats.finalize``.
 Lockstep waste counts, per quantum, Δ cycles for every SM that sits fully
 converged (no live warps, no in-flight memory requests) while the kernel
 as a whole is still running — the cycles the lockstep ``while_loop`` burns
 riding the longest SM/lane, the suspected cause of the batched-grid
 regression in ROADMAP's top open item.
+Memory chunks count the trips the memory phase makes over its due
+requests (sim/memsys.py: ceil(due / K) at the L2 slices plus the same at
+the DRAM channels, per quantum), so chunks per quantum show how often the
+phase needs more than one trip.
 
 The buffer lives INSIDE the traced program, so timelines ride every
 execution path unchanged: vmapped config lanes (core/sweep.py) carry a
@@ -56,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.sim import memsys
+
 # ---------------------------------------------------------------------------
 # counter layout
 # ---------------------------------------------------------------------------
@@ -68,7 +75,7 @@ CUM_SM = ("issued", "issued_mem", "l1_hit", "l1_miss", "cycles_issue",
 CUM_GLOBAL = ("l2_hit", "l2_miss", "dram_req", "dram_row_hit",
               "ctas_launched")
 # gauges: instantaneous / telemetry-only values
-GAUGES = ("active_warps", "lockstep_waste")
+GAUGES = ("active_warps", "lockstep_waste", "mem_chunks")
 COUNTERS = ("cycle",) + CUM_SM + CUM_GLOBAL + GAUGES
 N_COUNTERS = len(COUNTERS)
 # the columns that must equal stats.finalize totals in the final sample
@@ -83,13 +90,15 @@ def enabled(scfg) -> bool:
 
 def init(scfg) -> dict:
     """The ``telem`` state part: preallocated sample buffer + write index
-    + cumulative lockstep-waste accumulator.  Shapes depend only on the
-    telemetry knobs, so the part is replicated under 'sm' sharding and
-    vmaps over config/workload lanes like any other state."""
+    + cumulative lockstep-waste and memory-chunk accumulators.  Shapes
+    depend only on the telemetry knobs, so the part is replicated under
+    'sm' sharding and vmaps over config/workload lanes like any other
+    state."""
     return {
         "buf": jnp.zeros((scfg.telemetry_samples, N_COUNTERS), jnp.int32),
         "idx": jnp.zeros((), jnp.int32),
         "waste": jnp.zeros((), jnp.int32),
+        "chunks": jnp.zeros((), jnp.int32),
     }
 
 
@@ -107,6 +116,7 @@ def _row(telem: dict, state: dict, axis_name=None):
     vals += [jnp.asarray(state["stats"][k], jnp.int32) for k in CUM_GLOBAL]
     vals.append(_tot(state["warp"]["active"], axis_name))
     vals.append(telem["waste"])
+    vals.append(telem["chunks"])
     return jnp.stack(vals)
 
 
@@ -146,13 +156,20 @@ def sample(telem: dict, state: dict, scfg, axis_name=None,
     return dict(telem, buf=buf, idx=idx)
 
 
-def quantum_update(telem: dict, state: dict, trace: dict, scfg,
+def quantum_update(before: dict, state: dict, trace: dict, scfg,
                    axis_name=None) -> dict:
     """Per-quantum telemetry step, called at the end of every quantum body
-    (engine.quantum_step / the distributed kernel runners): accumulate
-    lockstep waste, then take a periodic sample."""
-    telem = dict(telem, waste=telem["waste"] + waste_increment(
-        state, trace["n_instr"], scfg, axis_name))
+    (engine.quantum_step / the distributed kernel runners) with the
+    quantum's input state ``before`` and its output ``state``: accumulate
+    lockstep waste and the memory phase's trips, then take a periodic
+    sample.  Returns the new ``telem`` part."""
+    telem = before["telem"]
+    telem = dict(
+        telem,
+        waste=telem["waste"] + waste_increment(state, trace["n_instr"],
+                                               scfg, axis_name),
+        chunks=telem["chunks"] + memsys.trips_between(
+            before["stats"], state["stats"], scfg))
     return sample(telem, state, scfg, axis_name)
 
 

@@ -2,46 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import given, settings, st
 
 from repro.sim.config import TINY, split_config
-from repro.sim.memsys import _lex_sort, _seg_maxplus, mem_phase
+from repro.sim.memsys import mem_phase
 from repro.sim.state import init_state
-
-
-@settings(max_examples=30, deadline=None)
-@given(st.lists(st.tuples(st.integers(0, 3), st.integers(0, 50),
-                          st.integers(1, 5)), min_size=1, max_size=40))
-def test_seg_maxplus_matches_loop(items):
-    """finish_i = max(arrival_i, finish_{i-1}) + service_i per segment."""
-    items.sort(key=lambda x: x[0])
-    seg = np.array([x[0] for x in items], np.int32)
-    arr = np.array([x[1] for x in items], np.int32)
-    srv = np.array([x[2] for x in items], np.int32)
-    seg_start = np.ones(len(items), bool)
-    seg_start[1:] = seg[1:] != seg[:-1]
-    got = np.asarray(_seg_maxplus(jnp.asarray(seg_start), jnp.asarray(srv),
-                                  jnp.asarray(arr)))
-    finish = {}
-    want = []
-    for s, a, v in items:
-        f = max(a, finish.get(s, 0)) + v
-        finish[s] = f
-        want.append(f)
-    assert (got == np.array(want)).all()
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.lists(st.tuples(st.integers(0, 3), st.integers(0, 100)),
-                min_size=1, max_size=30))
-def test_lex_sort(items):
-    p = jnp.asarray([x[0] for x in items], jnp.int32)
-    s = jnp.asarray([x[1] for x in items], jnp.int32)
-    t = jnp.arange(len(items), dtype=jnp.int32)
-    valid = jnp.ones(len(items), bool)
-    order = np.asarray(_lex_sort(p, s, t, valid))
-    keys = [(items[i][0], items[i][1], i) for i in order]
-    assert keys == sorted(keys)
 
 
 def _mem_phase_at(t0: int):
@@ -76,8 +40,8 @@ def _mem_phase_at(t0: int):
 
 
 def test_mem_phase_time_shift_invariance_past_int32_overflow():
-    """Regression for the _lex_sort int32 overflow: with ABSOLUTE event
-    time as the packed sort key, t ~ 2^25 × (r = n_sm·mshr rows) crosses
+    """Regression for the packed-key int32 overflow: with ABSOLUTE event
+    time in the canonical-order key, t ~ 2^25 × (r = n_sm·mshr rows) crosses
     2^31 and the service order silently scrambles.  Keying on
     quantum-relative time makes mem_phase exactly shift-equivariant: a
     run far past the old overflow point must replay the t0=0 run with

@@ -31,6 +31,7 @@ from repro.core import telemetry as T
 from repro.core.engine import simulate
 from repro.core.parallel import make_sm_runner
 from repro.core.sweep import grid_sweep, sweep, take_grid_lane, take_lane
+from repro.sim import memsys
 from repro.sim.config import TINY, split_config, static_part
 from repro.sim.state import init_state
 from repro.sim.workloads import zoo_workload
@@ -50,12 +51,20 @@ def tiny_workload(scale=0.02):
 
 def test_off_state_pytree_unchanged():
     assert not T.enabled(static_part(TINY))
-    assert "telem" not in init_state(TINY)
-    # and the finalize output grows no telemetry keys either
+    st0 = init_state(TINY)
+    assert "telem" not in st0
+    # the run grows no state part, and the memory phase's parts keep
+    # their leaves (its trip count lives in ``telem`` alone); finalize
+    # grows no telemetry keys either
     st = simulate(tiny_workload(), TINY, make_sm_runner(TINY, "vmap"),
                   max_cycles=MAX)
+    assert set(st) == set(st0)
+    for part in ("req", "mem", "stats"):
+        assert jax.tree_util.tree_structure(st[part]) == \
+            jax.tree_util.tree_structure(st0[part]), part
     out = S.finalize(st)
     assert "lockstep_waste" not in out
+    assert "mem_chunks" not in out
     assert "telemetry_samples" not in out
 
 
@@ -99,6 +108,32 @@ def test_final_sample_matches_finalize(mode):
     tl = T.timeline(st)
     cyc = tl[:, T.COUNTERS.index("cycle")]
     assert (np.diff(cyc) >= 0).all()
+
+
+def test_mem_chunks_counts_the_memory_phase_trips():
+    """``mem_chunks`` reads ceil(due_L2 / K) + ceil(due_DRAM / K) summed
+    over the quanta.  Sampled every quantum, each row's gain in the
+    served-request counters is that quantum's due counts (every due row
+    is served once), so the column is recomputed here from the other
+    columns.  48 MSHR rows an SM make K = 128 < the 384-row table, and
+    the mixed workload brings more than K due rows in some quantum."""
+    cfg = dataclasses.replace(TINY, mshr_per_sm=48, telemetry_samples=1024,
+                              telemetry_every=1)
+    k = memsys.chunk_rows(static_part(cfg))
+    assert k == 128 < cfg.n_sm * cfg.mshr_per_sm
+    st = simulate(tiny_workload(), cfg, make_sm_runner(cfg, "vmap"),
+                  max_cycles=MAX)
+    out = S.finalize(st)
+    assert T.check_final_sample(st, out) == []
+    assert out["telemetry_samples"] < cfg.telemetry_samples  # none lost
+    tl = T.timeline(st)
+    col = {name: tl[:, T.COUNTERS.index(name)] for name in T.COUNTERS}
+    due1 = np.diff(col["l2_hit"] + col["l2_miss"], prepend=0)
+    due2 = np.diff(col["dram_req"], prepend=0)
+    trips = -(-due1 // k) + -(-due2 // k)
+    assert due1.max() > k                         # a multi-trip quantum
+    assert np.array_equal(col["mem_chunks"], np.cumsum(trips))
+    assert out["mem_chunks"] == col["mem_chunks"][-1] > 0
 
 
 def test_seq_vmap_timelines_identical():

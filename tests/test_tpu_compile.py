@@ -32,6 +32,7 @@ from repro.core.plan import RunPlan
 from repro.core.sweep import batched_init, make_pair_runner, stack_dyn
 from repro.launch.dse import default_grid
 from repro.sim.config import RTX3080TI, split_config
+from repro.sim.memsys import mem_phase
 from repro.sim.smcore import sm_cycle_single
 from repro.sim.state import init_state
 from repro.sim.workloads import zoo_workload
@@ -155,6 +156,77 @@ def test_sm_cycle_compiles_without_scatters_for_v5e(one_chip):
     gathers = [line for line in body if uses(comps, line, "gather")]
     assert not scatters, scatters[:3]
     assert len(gathers) <= 8, len(gathers)
+
+
+# instructions that move no data and run no kernel, left out of op counts
+TRIVIAL = {"get-tuple-element", "bitcast", "constant", "parameter", "tuple"}
+HLO_NAME_TYPE = re.compile(r"^\s+(?:ROOT\s+)?%([^\s=]+)\s*=\s*(.*?)\s"
+                           r"[a-z][\w-]*\(")
+HLO_OPERANDS = re.compile(r"\s[a-z][\w-]*\(([^)]*)\)")
+
+
+def executed(comps, text):
+    """The computations a program runs op by op: its entry and the
+    bodies and conditions of the ``while`` loops those hold (a fusion's
+    computation is one kernel of its caller, and not among them)."""
+    todo = [re.search(r"^ENTRY\s+%(\S+)", text, re.M).group(1)]
+    done = []
+    while todo:
+        name = todo.pop()
+        done.append(name)
+        for line in comps[name]:
+            if HLO_OPCODE.match(line).group(1) == "while":
+                todo += [c for c in re.findall(
+                    r"\b(?:body|condition)=%([^\s,]+)", line)
+                    if c not in done + todo]
+    return done
+
+
+def index_ops_over(comps, rows):
+    """Sort, gather and scatter instructions, inside fusions too, with a
+    ``rows``-long dimension in their result or in an operand."""
+    found = []
+    for lines in comps.values():
+        types = {HLO_NAME_TYPE.match(x).group(1): HLO_NAME_TYPE.match(x)
+                 .group(2) for x in lines}
+        for line in lines:
+            if HLO_OPCODE.match(line).group(1) not in ("sort", "gather",
+                                                        "scatter"):
+                continue
+            args = re.findall(r"%([^\s,]+)",
+                              HLO_OPERANDS.search(line).group(1))
+            shapes = [HLO_NAME_TYPE.match(line).group(2)] + \
+                [types.get(a, "") for a in args]
+            if any(str(rows) in re.findall(r"\d+", ",".join(
+                    re.findall(r"\[([\d,]*)\]", sh))) for sh in shapes):
+                found.append(line.strip()[:160])
+    return found
+
+
+def test_mem_phase_compiles_without_whole_table_index_ops(one_chip):
+    """The memory phase serves the due requests in 128-row chunks through
+    one-hot masks, so ``mem_phase`` alone at the 80-SM shapes (80 x 32 =
+    2,560 request rows), compiled for one v5e, holds no sort, gather or
+    scatter over the request table, and at most 150 non-trivial ops in
+    the computations it runs op by op (the sorted and scanned
+    formulation before it: 497, 4 sorts, 17 gathers and 11 scatters of
+    2,560 rows)."""
+    scfg, dyn = split_config(RTX3080TI)
+    rows = scfg.n_sm * scfg.mshr_per_sm
+    assert rows == 2560
+    state = jax.eval_shape(partial(init_state, scfg))
+    phase = jax.jit(lambda req, mem, stats, t0, dyn, sm_ids: mem_phase(
+        req, mem, stats, t0, scfg, dyn, sm_ids=sm_ids))
+    args = (state["req"], state["mem"], state["stats"],
+            jax.ShapeDtypeStruct((), jnp.int32), dyn,
+            state["ctrl"]["sm_ids"])
+    text = phase.lower(*shapes_of(args, one_chip)).compile().as_text()
+    comps = hlo_computations(text)
+    ops = [line for c in executed(comps, text) for line in comps[c]
+           if HLO_OPCODE.match(line).group(1) not in TRIVIAL]
+    assert any(HLO_OPCODE.match(line).group(1) == "while" for line in ops)
+    assert index_ops_over(comps, rows) == []
+    assert len(ops) <= 150, len(ops)
 
 
 def test_sm_cycle_jaxpr_gathers_only_from_the_trace():
